@@ -11,7 +11,12 @@ plus MODEL_FLOPS = 6 * N(_active) * tokens (train) or 2 * N_active * tokens
 (inference) with an explicit attention/SSM correction, and the useful-compute
 ratio MODEL_FLOPS / (HLO_FLOPs * chips).
 
+The peaks are the running device's (``repro.core.hw.chip_for``), or those
+of the chip named by ``--device-kind`` (a key of ``repro.core.hw.CHIPS``,
+e.g. "TPU v5 lite"); with neither, the roofline is "not measured".
+
 Usage: PYTHONPATH=src python -m benchmarks.roofline [--dir ...] [--md]
+       [--device-kind KIND]
 """
 from __future__ import annotations
 
@@ -22,9 +27,7 @@ import os
 
 from repro.configs import get_config
 from repro.configs.base import SHAPES
-from repro.core.hw import TPU_V5E
-
-CHIP = TPU_V5E
+from repro.core import hw
 
 
 def model_flops(arch: str, shape_name: str) -> float:
@@ -76,7 +79,7 @@ def _decode_attn_flops(cfg, S: int) -> float:
     return total
 
 
-def analyze(rec: dict) -> dict:
+def analyze(rec: dict, chip: hw.ChipSpec) -> dict:
     chips = rec["chips"]
     if "corrected" in rec:
         # loop-corrected HLO walker (XLA's cost_analysis counts while bodies
@@ -88,9 +91,9 @@ def analyze(rec: dict) -> dict:
         flops_dev = rec["cost"]["flops_per_device"]
         bytes_dev = rec["cost"]["bytes_per_device"]
         coll_dev = rec["collectives"]["total_bytes"]
-    compute_s = flops_dev / CHIP.peak_flops
-    memory_s = bytes_dev / CHIP.hbm_bw
-    collective_s = coll_dev / CHIP.ici_bw_per_link
+    compute_s = flops_dev / chip.peak_flops
+    memory_s = bytes_dev / chip.hbm_bw
+    collective_s = coll_dev / chip.ici_bw_per_link
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dominant = max(terms, key=terms.get)
@@ -104,12 +107,12 @@ def analyze(rec: dict) -> dict:
         "hlo_flops_global": hlo_global,
         "useful_ratio": round(mf / hlo_global, 4) if hlo_global else 0.0,
         "roofline_fraction": round(
-            (mf / chips / CHIP.peak_flops) / bound_s, 4) if bound_s else 0.0,
+            (mf / chips / chip.peak_flops) / bound_s, 4) if bound_s else 0.0,
         "step_lower_bound_s": round(bound_s, 6),
     }
 
 
-def load(dir_: str, mesh: str = "16x16") -> list[dict]:
+def load(dir_: str, chip: hw.ChipSpec, mesh: str = "16x16") -> list[dict]:
     out = []
     for path in sorted(glob.glob(os.path.join(dir_, f"*_{mesh}.json"))):
         with open(path) as f:
@@ -117,7 +120,7 @@ def load(dir_: str, mesh: str = "16x16") -> list[dict]:
         if "error" in rec:
             out.append(rec)
             continue
-        rec["roofline"] = analyze(rec)
+        rec["roofline"] = analyze(rec, chip)
         out.append(rec)
     return out
 
@@ -148,8 +151,15 @@ def main() -> None:
     ap.add_argument("--dir", default="benchmarks/results/dryrun")
     ap.add_argument("--mesh", default="16x16")
     ap.add_argument("--md", action="store_true")
+    ap.add_argument("--device-kind", choices=sorted(hw.CHIPS), default=None,
+                    help="peaks of this chip instead of the running device's")
     args = ap.parse_args()
-    recs = load(args.dir, args.mesh)
+    chip = hw.CHIPS[args.device_kind] if args.device_kind else hw.chip_for()
+    if chip is None:
+        print("roofline: not measured (no accelerator; name the target "
+              "chip with --device-kind)")
+        return
+    recs = load(args.dir, chip, args.mesh)
     if args.md:
         print(as_markdown(recs))
     else:

@@ -12,6 +12,8 @@ import argparse
 import json
 from pathlib import Path
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def bench_analysis(out_path: str | Path = "BENCH_analysis.json") -> dict:
     """Time the full analyzer suite; write wall-time + per-pass finding
@@ -35,6 +37,7 @@ def main() -> None:
     ap.add_argument("--analysis-out", default="BENCH_analysis.json",
                     help="where bench_analysis writes its JSON")
     args = ap.parse_args()
+    use_compile_cache()
 
     from benchmarks.kernels_bench import bench_kernels
     from benchmarks.paper import ALL_BENCHES

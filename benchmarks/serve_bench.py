@@ -37,6 +37,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.serve.engine import Request, ServeEngine, WaveServeEngine
 
@@ -116,6 +117,7 @@ def main() -> None:
                          "5%% tokens/s for full tracing, 2%% for sampled "
                          "(sample_every=16) mode")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch, reduced=True)
     params = T.init_params(jax.random.PRNGKey(0), cfg)

@@ -1,18 +1,23 @@
 """Hardware constants used by the mapper and the roofline analysis.
 
-TPU v5e per chip (the framework's execution target):
-  * 197 TFLOP/s bf16 peak (MXU)
-  * 819 GB/s HBM bandwidth
-  * ~50 GB/s/link ICI (per-direction, per-link)
-  * ~16 GiB HBM, ~128 MiB VMEM budget per core is conservative; we tile for
-    a 16 MiB working-set budget per kernel invocation.
+TPU v5e per chip (Google Cloud documentation, "TPU v5e"):
+  * 197 TFLOP/s bf16 peak (MXU), 393 TOP/s int8
+  * 16 GB HBM at 819 GB/s
+  * 1,600 Gbit/s chip-to-chip interconnect (4 links of ~50 GB/s)
+  * we tile for a 16 MiB working-set budget per kernel invocation.
 
 The MXU is itself a 128x128 systolic array -- the natural "array shape" for
 the paper's runtime model when reasoning about TPU GeMM mapping.
+
+Roofline peaks are looked up by the running device's ``device_kind``
+(:func:`chip_for`); an accelerator missing from :data:`CHIPS` is an error,
+never a silent v5e default.
 """
 from __future__ import annotations
 
 import dataclasses
+
+import jax
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,5 +43,40 @@ TPU_V5E = ChipSpec(
     mxu_shape=(128, 128),
 )
 
+# Peaks keyed by ``jax.Device.device_kind``.
+CHIPS: dict[str, ChipSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def chip_for(device=None) -> ChipSpec | None:
+    """Published peaks of ``device`` (default: the first JAX device).
+
+    None on the CPU, whose runs have no roofline ("not measured"); an
+    accelerator whose ``device_kind`` is not in :data:`CHIPS` raises."""
+    device = jax.devices()[0] if device is None else device
+    if device.platform == "cpu":
+        return None
+    try:
+        return CHIPS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device.device_kind!r}; "
+            f"add it to repro.core.hw.CHIPS (known: {sorted(CHIPS)})"
+        ) from None
+
+
 # Working-set budget a single pallas_call block set should stay under.
 VMEM_TILE_BUDGET = 16 * 1024**2
+
+def legal_block(dim: int, target: int, align: int) -> int:
+    """Block extent along one array dim that Mosaic can tile.
+
+    Mosaic takes a block whose last dim is a multiple of 128, whose
+    second-to-last is a multiple of the dtype's sublane count (8 rows of
+    32-bit, 16 of 16-bit), or either equal to the array dim.  This returns
+    the whole dim when ``target`` covers it (no padding); otherwise
+    ``target`` rounded down to a multiple of ``align`` (at least ``align``),
+    and the caller pads the dim up to a multiple of the result."""
+    if target >= dim:
+        return dim
+    b = max(align, target // align * align)
+    return dim if b >= dim else b

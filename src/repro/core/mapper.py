@@ -72,13 +72,6 @@ class TpuBlocking:
     vmem_bytes: int            # resident working set
 
 
-def _round_block(dim: int, target: int, multiple: int) -> int:
-    """Largest multiple of ``multiple`` <= min(dim_padded, target)."""
-    b = min(dim, target)
-    b = max(multiple, (b // multiple) * multiple)
-    return b
-
-
 def modeled_traffic(shape: GemmShape, bm: int, bk: int, bn: int,
                     loop_order: Dataflow, bytes_per_elem: int = 2) -> int:
     """HBM operand traffic of a blocked GeMM under a given loop order.
@@ -115,9 +108,10 @@ def select_tpu_blocking(
 ) -> TpuBlocking:
     """Pick (bm, bk, bn) + loop order minimizing modeled HBM traffic.
 
-    Blocks are multiples of the MXU tile (128) where the dim allows; the
-    fp32 accumulator (bm x bn x 4B) plus both operand blocks (double
-    buffered) must fit the VMEM budget.
+    Every block is tileable by Mosaic: a multiple of the MXU tile (128) or
+    the whole dim (:func:`repro.core.hw.legal_block`).  The fp32
+    accumulator (bm x bn x 4B) plus both operand blocks (double buffered)
+    must fit the VMEM budget.
 
     Decisions are LRU-cached per (shape, bytes_per_elem, budget, chip): the
     exhaustive candidate sweep runs once per unique key per process, not per
@@ -176,9 +170,9 @@ def _select_tpu_blocking_cached(
     for bm in (128, 256, 512):
         for bn in (128, 256, 512):
             for bk in (128, 256, 512, 1024, 2048):
-                bm_ = _round_block(shape.M, bm, min(lane, _pow2_floor(shape.M)))
-                bn_ = _round_block(shape.N, bn, min(lane, _pow2_floor(shape.N)))
-                bk_ = _round_block(shape.K, bk, min(lane, _pow2_floor(shape.K)))
+                bm_ = hw.legal_block(shape.M, bm, lane)
+                bn_ = hw.legal_block(shape.N, bn, lane)
+                bk_ = hw.legal_block(shape.K, bk, lane)
                 acc = bm_ * bn_ * 4
                 operands = 2 * (bm_ * bk_ + bk_ * bn_) * bytes_per_elem  # 2x: dbl buffer
                 vmem = acc + operands
@@ -202,9 +196,3 @@ def _select_tpu_blocking_cached(
     candidates.sort(key=lambda c: (c.hbm_traffic_bytes, -(c.bm * c.bn * c.bk)))
     return candidates[0]
 
-
-def _pow2_floor(x: int) -> int:
-    p = 1
-    while p * 2 <= x:
-        p *= 2
-    return min(p, 128) if p >= 1 else 1

@@ -128,7 +128,8 @@ def flash_attention_fwd(
 
 
 def _fa_int8_kernel(q_ref, k_ref, v_ref, mask_ref, sqk_ref, sv_ref, o_ref,
-                    m_ref, l_ref, acc_ref, *, nk: int):
+                    m_ref, l_ref, acc_ref, *, nk: int, rep: int):
+    h = pl.program_id(1)
     ki = pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -142,7 +143,7 @@ def _fa_int8_kernel(q_ref, k_ref, v_ref, mask_ref, sqk_ref, sv_ref, o_ref,
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32
-    ).astype(jnp.float32) * sqk_ref[0, 0]           # dequant + softmax scale
+    ).astype(jnp.float32) * sqk_ref[h]              # dequant + softmax scale
     s = jnp.where(mask_ref[0], s, _NEG_INF)
 
     m_prev = m_ref[...]
@@ -160,7 +161,7 @@ def _fa_int8_kernel(q_ref, k_ref, v_ref, mask_ref, sqk_ref, sv_ref, o_ref,
         pq, v_ref[0, 0], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
     acc_ref[...] = (acc_ref[...] * corr
-                    + pv.astype(jnp.float32) * (sv_ref[0, 0] / 127.0))
+                    + pv.astype(jnp.float32) * (sv_ref[h // rep] / 127.0))
 
     @pl.when(ki == nk - 1)
     def _store():
@@ -169,13 +170,13 @@ def _fa_int8_kernel(q_ref, k_ref, v_ref, mask_ref, sqk_ref, sv_ref, o_ref,
 
 
 def _per_head_quantize(x: jax.Array, qmax: float = 127.0):
-    """(B, H, S, dh) float -> (int8 payload, (H, 1) f32 per-head scale)."""
+    """(B, H, S, dh) float -> (int8 payload, (H,) f32 per-head scale)."""
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=(0, 2, 3))
     scale = jnp.maximum(amax, _EPS) / qmax                     # (H,)
     xq = jnp.clip(jnp.round(x.astype(jnp.float32)
                             / scale[None, :, None, None]), -qmax, qmax
                   ).astype(jnp.int8)
-    return xq, scale[:, None].astype(jnp.float32)
+    return xq, scale.astype(jnp.float32)
 
 
 def int8_flash_attention_fwd(
@@ -206,11 +207,11 @@ def int8_flash_attention_fwd(
     bq = min(block_q, Sq)
     bkv = min(block_kv, Skv)
 
-    qq, sq = _per_head_quantize(q)                 # (H, 1)
-    kq, sk = _per_head_quantize(k)                 # (KvH, 1)
-    vq, sv = _per_head_quantize(v)                 # (KvH, 1)
+    qq, sq = _per_head_quantize(q)                 # (H,)
+    kq, sk = _per_head_quantize(k)                 # (KvH,)
+    vq, sv = _per_head_quantize(v)                 # (KvH,)
     # combined per-q-head dequant scale for the score block
-    sqk = sq * sk[jnp.arange(H) // rep] * sm_scale  # (H, 1)
+    sqk = sq * sk[jnp.arange(H) // rep] * sm_scale  # (H,)
 
     if mask is None:
         q_idx = jnp.arange(Sq)
@@ -227,7 +228,7 @@ def int8_flash_attention_fwd(
     nk = k_p.shape[2] // bkv
 
     out = pl.pallas_call(
-        functools.partial(_fa_int8_kernel, nk=nk),
+        functools.partial(_fa_int8_kernel, nk=nk, rep=rep),
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, dh),
@@ -237,8 +238,10 @@ def int8_flash_attention_fwd(
             pl.BlockSpec((1, 1, bkv, dh),
                          lambda b, h, qi, ki, rep=rep: (b, h // rep, ki, 0)),
             pl.BlockSpec((1, bq, bkv), lambda b, h, qi, ki: (b, qi, ki)),
-            pl.BlockSpec((1, 1), lambda b, h, qi, ki: (h, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, qi, ki, rep=rep: (h // rep, 0)),
+            # per-head scalars, whole in SMEM (a (1, 1) VMEM block of an
+            # (H, 1) array is not a tile the chip's compiler accepts)
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, dh),
                                lambda b, h, qi, ki: (b, h, qi, 0)),

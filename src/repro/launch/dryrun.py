@@ -180,7 +180,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
         result["serve_chunk"] = serve_chunk
     t0 = time.time()
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params_shape = jax.eval_shape(
             lambda: T.init_params(jax.random.PRNGKey(0), cfg))
         params_shardings = shd.param_sharding(params_shape, mesh, spec_fn)

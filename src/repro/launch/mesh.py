@@ -10,20 +10,12 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where this jax has it (>= 0.5); older versions
-    treat every mesh axis as Auto already."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 (one v5e pod's worth of chips) or 2x16x16 (two pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):

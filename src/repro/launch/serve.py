@@ -29,6 +29,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.serve.engine import (
     QUEUE_POLICIES,
@@ -41,7 +42,10 @@ from repro.serve.engine import (
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_IDS), default="yi-9b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced same-family config (CPU-sized; the "
+                         "default); --no-reduced serves published widths")
     ap.add_argument("--engine", choices=("continuous", "wave"),
                     default="continuous")
     ap.add_argument("--requests", type=int, default=8)
@@ -85,6 +89,7 @@ def main() -> None:
     ap.add_argument("--stream-interval", type=float, default=None,
                     help="seconds between streaming snapshots")
     args = ap.parse_args()
+    use_compile_cache()
 
     obs_on = bool(args.trace_out or args.metrics_out or args.prom_out
                   or args.stream_dir or args.profile_dir

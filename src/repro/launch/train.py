@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data import SyntheticLMDataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.optim import adamw
 from repro.train.train_step import init_train_state, make_train_step
 from repro.train.trainer import Trainer
@@ -39,6 +40,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     logging.basicConfig(level=logging.INFO)
     cfg = get_config(args.arch, reduced=args.reduced)

@@ -38,6 +38,8 @@ import contextlib
 
 import jax
 
+from repro.obs.metrics import outside_trace
+
 __all__ = ["scope", "host_scope"]
 
 
@@ -51,7 +53,7 @@ def _capturing() -> bool:
 @contextlib.contextmanager
 def scope(name: str):
     """Label everything staged (or run) inside the block with ``name``."""
-    if jax.core.trace_state_clean() and _capturing():
+    if outside_trace() and _capturing():
         with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
             yield
     else:
@@ -67,7 +69,7 @@ def host_scope(name: str, *, enabled: bool = True):
     running, or when called under a trace -- an engine can wrap its step
     dispatch unconditionally and stay a true no-op with telemetry off.
     """
-    if enabled and jax.core.trace_state_clean() and _capturing():
+    if enabled and outside_trace() and _capturing():
         with jax.profiler.TraceAnnotation(name):
             yield
     else:

@@ -8,15 +8,13 @@ each eager kernel call through ``jax.block_until_ready`` and records a
 the two per kernel kind per backend and reports:
 
   * achieved FLOP/s and achieved bytes/s (modeled volume / measured wall);
-  * roofline placement against a :class:`~repro.core.hw.ChipSpec` (ridge
-    point = peak_flops / hbm_bw; modeled time = max(compute, traffic));
+  * roofline placement against the running device's published peaks
+    (:func:`repro.core.hw.chip_for`; ridge point = peak_flops / hbm_bw;
+    modeled time = max(compute, traffic));
   * modeled-vs-measured time error (``measured_wall_s / modeled_time_s``).
 
-On this repo's CPU/interpret-mode CI the error ratios are enormous and
-that is the point: they quantify exactly how far the execution substrate
-sits from the paper's modeled ASIC/TPU, per kernel kind, instead of
-leaving the analytic claims untethered.  The same join run on a real TPU
-backend is the validation the ROADMAP's arena comparisons need.
+A CPU run has no chip peaks: its roofline fields read "not measured"
+rather than placing CPU walls against a TPU's peaks.
 
 Jitted steps never hit the ring (one dispatch per compilation), so their
 modeled cost arrives via ``optrace.traced_costs()``; the serve/vision
@@ -29,22 +27,33 @@ from __future__ import annotations
 import json
 from typing import Any
 
-from repro.core.hw import TPU_V5E, ChipSpec
+from repro.core.hw import ChipSpec, chip_for
 from repro.obs import optrace
 
 # wall scopes recorded by the dispatcher under measure_dispatch
 WALL_PREFIX = "dispatch:"
+NOT_MEASURED = "not measured"
 
 
-def _chip_info(chip: ChipSpec) -> dict[str, Any]:
+def _chip(chip: ChipSpec | None) -> ChipSpec | None:
+    """``chip``, or the running device's peaks (None on the CPU)."""
+    return chip if chip is not None else chip_for()
+
+
+def _chip_info(chip: ChipSpec | None) -> dict[str, Any] | str:
+    if chip is None:
+        return NOT_MEASURED
     return {"name": chip.name, "peak_flops": chip.peak_flops,
             "hbm_bw": chip.hbm_bw,
             "ridge_flops_per_byte": chip.peak_flops / chip.hbm_bw}
 
 
-def _roofline(flops: float, nbytes: float, chip: ChipSpec
-              ) -> tuple[float, str]:
-    """(modeled seconds, placement) for a modeled (flops, bytes) volume."""
+def _roofline(flops: float, nbytes: float, chip: ChipSpec | None
+              ) -> tuple[float | None, str]:
+    """(modeled seconds, placement) for a modeled (flops, bytes) volume;
+    (None, "not measured") without chip peaks."""
+    if chip is None:
+        return None, NOT_MEASURED
     t_compute = flops / chip.peak_flops if flops else 0.0
     t_traffic = nbytes / chip.hbm_bw if nbytes else 0.0
     placement = "compute-bound" if t_compute >= t_traffic else "memory-bound"
@@ -65,7 +74,7 @@ def measured_walls() -> dict[tuple[str, str], dict[str, float]]:
     return out
 
 
-def kind_rows(chip: ChipSpec = TPU_V5E) -> list[dict[str, Any]]:
+def kind_rows(chip: ChipSpec | None = None) -> list[dict[str, Any]]:
     """One attribution row per (kind, backend) seen in the op ring.
 
     Under ring sampling the modeled sums cover only the sampled events;
@@ -86,6 +95,7 @@ def kind_rows(chip: ChipSpec = TPU_V5E) -> list[dict[str, Any]]:
     for key in walls:
         groups.setdefault(key, {"count": 0, "flops": 0.0,
                                 "bytes": 0.0, "energy_j": 0.0})
+    chip = _chip(chip)
     rows = []
     for (kind, backend), g in sorted(groups.items()):
         modeled_t, placement = _roofline(g["flops"], g["bytes"], chip)
@@ -110,14 +120,15 @@ def kind_rows(chip: ChipSpec = TPU_V5E) -> list[dict[str, Any]]:
         if w and w["wall_s"] > 0:
             row["achieved_flops_per_s"] = g["flops"] / w["wall_s"]
             row["achieved_bytes_per_s"] = g["bytes"] / w["wall_s"]
-            if modeled_t > 0:
+            if modeled_t:
                 row["time_error_ratio"] = w["wall_s"] / modeled_t
         rows.append(row)
     return rows
 
 
-def report(chip: ChipSpec = TPU_V5E) -> dict[str, Any]:
+def report(chip: ChipSpec | None = None) -> dict[str, Any]:
     """The full attribution report (what ``attribution.json`` holds)."""
+    chip = _chip(chip)
     rows = kind_rows(chip)
     total = _totals(rows)
     return {
@@ -144,7 +155,7 @@ def _totals(rows: list[dict[str, Any]]) -> dict[str, Any]:
     return tot
 
 
-def write_json(path: str, chip: ChipSpec = TPU_V5E) -> dict[str, Any]:
+def write_json(path: str, chip: ChipSpec | None = None) -> dict[str, Any]:
     rep = report(chip)
     with open(path, "w") as f:
         json.dump(rep, f, indent=1, sort_keys=True)
@@ -152,7 +163,7 @@ def write_json(path: str, chip: ChipSpec = TPU_V5E) -> dict[str, Any]:
 
 
 def engine_row(*, wall_s: float, modeled: dict[str, float], steps: int,
-               covered_steps: int, chip: ChipSpec = TPU_V5E
+               covered_steps: int, chip: ChipSpec | None = None
                ) -> dict[str, Any]:
     """The achieved-intensity row engines put in ``last_stats``.
 
@@ -162,6 +173,7 @@ def engine_row(*, wall_s: float, modeled: dict[str, float], steps: int,
     signature was traced before telemetry was enabled contribute wall
     time but no modeled volume, and the coverage ratio says so.
     """
+    chip = _chip(chip)
     flops = modeled.get("flops", 0.0)
     nbytes = modeled.get("bytes", 0.0)
     modeled_t, placement = _roofline(flops, nbytes, chip)
@@ -176,20 +188,21 @@ def engine_row(*, wall_s: float, modeled: dict[str, float], steps: int,
         "achieved_flops_per_s": flops / wall_s if wall_s > 0 else None,
         "achieved_bytes_per_s": nbytes / wall_s if wall_s > 0 else None,
         "time_error_ratio":
-            wall_s / modeled_t if wall_s > 0 and modeled_t > 0 else None,
+            wall_s / modeled_t if wall_s > 0 and modeled_t else None,
         "modeled_step_coverage":
             covered_steps / steps if steps else 0.0,
-        "chip": chip.name,
+        "chip": chip.name if chip is not None else NOT_MEASURED,
     }
     return row
 
 
-def paper_section(chip: ChipSpec = TPU_V5E) -> dict[str, Any]:
+def paper_section(chip: ChipSpec | None = None) -> dict[str, Any]:
     """The ``paper_report["attribution"]`` section: measured kinds only.
 
     The analytic paper report stands on its own; this section tethers it
     to measurement when telemetry carries any, and says why not when it
     does not."""
+    chip = _chip(chip)
     rows = [r for r in kind_rows(chip) if r["measured_wall_s"]]
     if not rows:
         return {"available": False,

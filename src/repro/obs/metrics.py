@@ -37,10 +37,16 @@ DEFAULT_BUCKETS = (1e-4, 5e-4, 1e-3, 5e-3, 1e-2, 5e-2, 0.1, 0.5, 1.0, 5.0,
                    10.0, 60.0)
 
 
+def outside_trace() -> bool:
+    """True at the top level: no ``jax.jit`` / ``jax.eval_shape`` /
+    transformation trace is being staged in this thread."""
+    return jax.core.trace_ctx.is_top_level()
+
+
 def host_clean(*values: Any) -> bool:
     """True when recording is safe: no JAX trace is being staged and none
     of ``values`` is an abstract ``Tracer``."""
-    if not jax.core.trace_state_clean():
+    if not outside_trace():
         return False
     return not any(isinstance(v, jax.core.Tracer) for v in values)
 

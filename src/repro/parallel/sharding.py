@@ -21,26 +21,15 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def current_mesh() -> Mesh | None:
-    """The ambient ``with mesh:`` context, or None off-mesh.
+def current_mesh():
+    """The mesh set by ``jax.set_mesh``, or None off-mesh.
 
-    Reads the public ``jax.interpreters.pxla`` thread resources (stable
-    across 0.4.x); falls back to the private module only if a future jax
-    moves the public alias, and degrades to "no mesh" rather than raising
-    -- every caller treats None as single-device."""
-    try:
-        from jax.interpreters import pxla
-        env = pxla.thread_resources.env
-    except (ImportError, AttributeError):
-        try:
-            from jax._src import mesh as mesh_lib
-            env = mesh_lib.thread_resources.env
-        except (ImportError, AttributeError):
-            return None
-    m = getattr(env, "physical_mesh", None)
-    if m is None or m.empty:
-        return None
-    return m
+    Returned as the ``AbstractMesh`` (axis names and sizes) because that is
+    what stays readable inside ``jax.jit`` tracing, where ``constrain`` runs.
+    The legacy ``with mesh:`` context is not consulted: enter meshes with
+    ``jax.set_mesh``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def fsdp_axes(mesh: Mesh) -> tuple[str, ...]:
@@ -86,24 +75,37 @@ def constrain(x: jax.Array, *entries) -> jax.Array:
     mesh = current_mesh()
     if mesh is None:
         return x
-    expanded = []
-    for e in entries:
-        if e in ("fsdp", "batch"):
-            expanded.append(fsdp_axes(mesh))
-        else:
-            expanded.append(e)
-    spec = resolve(mesh, tuple(expanded), x.shape)
+    spec = resolve(mesh, _expand(mesh, entries), x.shape)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+def _expand(mesh: Mesh, entries) -> tuple:
+    """Logical markers 'fsdp' / 'batch' -> the mesh's (pod, data) axes."""
+    return tuple(fsdp_axes(mesh) if e in ("fsdp", "batch") else e
+                 for e in entries)
+
+
 def named_sharding(mesh: Mesh, *entries, dims: tuple[int, ...]) -> NamedSharding:
-    expanded = []
-    for e in entries:
-        if e in ("fsdp", "batch"):
-            expanded.append(fsdp_axes(mesh))
-        else:
-            expanded.append(e)
-    return NamedSharding(mesh, resolve(mesh, tuple(expanded), dims))
+    return NamedSharding(mesh, resolve(mesh, _expand(mesh, entries), dims))
+
+
+def per_device(fn, operands, in_entries, out_entries, out_dims):
+    """``fn(*operands)``, run on each device's shard under the ambient mesh.
+
+    XLA cannot partition a Pallas (Mosaic) kernel, so under a mesh a kernel
+    call goes through ``jax.shard_map``.  ``in_entries`` holds one tuple of
+    logical entries per operand and ``out_entries`` one for the result of
+    shape ``out_dims`` (entries as for :func:`constrain`).  An axis that
+    does not divide its dim is dropped, so that dim reaches the kernel
+    whole.  Off-mesh this is a plain call."""
+    mesh = current_mesh()
+    if mesh is None:
+        return fn(*operands)
+    in_specs = tuple(resolve(mesh, _expand(mesh, e), x.shape)
+                     for e, x in zip(in_entries, operands))
+    out_spec = resolve(mesh, _expand(mesh, out_entries), out_dims)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
+                         check_vma=False)(*operands)
 
 
 def constrain_priority(x: jax.Array, batch_dims: int, candidates: list[int],

@@ -417,7 +417,7 @@ class ServeEngine:
         mesh = self.mesh
 
         def wrapped(*args, **kwargs):
-            with mesh:
+            with jax.set_mesh(mesh):
                 return fn(*args, **kwargs)
 
         return wrapped
@@ -436,6 +436,20 @@ class ServeEngine:
         if not self.decouple_prefill:
             return ()
         return declared_prefill_widths(self.prefill_chunk)
+
+    def lower_step(self, width: int):
+        """The chunk step lowered at token ``width`` against this engine's
+        parameters and cache layout; ``.compile()`` it to inspect the
+        program the device runs.  Single-device engines only."""
+        if self.mesh is not None:
+            raise NotImplementedError("lower_step: single-device engines only")
+        B = self.batch_slots
+        caches = jax.eval_shape(lambda: T.init_caches(
+            self.cfg, batch=B, max_len=self.max_len, dtype=self.cache_dtype,
+            paged=self.paged))
+        tokens = jax.ShapeDtypeStruct((B, width), jnp.int32)
+        valid = jax.ShapeDtypeStruct((B, width), jnp.bool_)
+        return self._step.lower(self.params, caches, tokens, valid, self.rng)
 
     # ------------------------------------------------------------- schedule
 
@@ -607,6 +621,7 @@ class ServeEngine:
                 slots[b] = _Slot()              # freed: backfilled next step
 
         steps = 0
+        steps_by_width: collections.Counter = collections.Counter()
         n_prefill = 0
         modeled = {"flops": 0.0, "bytes": 0.0, "energy_j": 0.0}
         covered_steps = 0
@@ -663,6 +678,7 @@ class ServeEngine:
                         "decode_slots": sum(
                             1 for s in slots if s.state == "decode")})
             steps += 1
+            steps_by_width[C] += 1
             n_prefill += sum(fed)
             for b, s in enumerate(slots):
                 if s.state == "prefill":
@@ -679,6 +695,7 @@ class ServeEngine:
         self.last_stats = {
             "requests": per_req,
             "steps": steps,
+            "steps_by_width": dict(steps_by_width),
             "wall_s": wall,
             "generated_tokens": n_tok,
             "tokens_per_s": n_tok / wall if wall > 0 else 0.0,

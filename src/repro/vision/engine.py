@@ -137,16 +137,27 @@ class VisionEngine:
         """Batch dims this engine's infer step will ever be traced at."""
         return declared_step_batches(self.batch_slots)
 
+    def lower_step(self):
+        """The infer step lowered at its one batch shape; ``.compile()`` it
+        to inspect the program the device runs.  Single-device engines
+        only."""
+        if self.mesh is not None:
+            raise NotImplementedError("lower_step: single-device engines only")
+        images = jax.ShapeDtypeStruct(
+            (self.batch_slots, *self.cfg.input_hw, self.cfg.in_channels),
+            self.cfg.pdtype)
+        return self._step.lower(self.params, images)
+
     def _under_mesh(self, fn):
         """Wrap a jitted callable so every call (and hence every trace)
-        runs inside ``with mesh:`` -- arming the model-level ``constrain``
-        annotations without touching the scheduling loop."""
+        runs inside ``jax.set_mesh(mesh)`` -- arming the model-level
+        ``constrain`` annotations without touching the scheduling loop."""
         if self.mesh is None:
             return fn
         mesh = self.mesh
 
         def wrapped(*args, **kwargs):
-            with mesh:
+            with jax.set_mesh(mesh):
                 return fn(*args, **kwargs)
 
         return wrapped

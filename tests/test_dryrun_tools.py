@@ -127,12 +127,8 @@ class TestParamSpecs:
 
 
 def _make_mesh(shape, names):
-    """jax.make_mesh across versions: axis_types only exists on newer jax."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, names,
-                             axis_types=(axis_type.Auto,) * len(names))
-    return jax.make_mesh(shape, names)
+    return jax.make_mesh(shape, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 class TestResolveGuards:

@@ -5,7 +5,8 @@ The load-bearing properties, in order of blast radius:
   1. a paged float engine is TOKEN-IDENTICAL to the dense engine under
      greedy decoding (GQA, SWA, MLA) -- paging is pure data movement;
   2. int8 page payloads (per-token-per-head scales, dequant-on-read) stay
-     token-identical on the same archetypes at reduced test scale;
+     token-identical on the same archetypes at reduced test scale, up to
+     a top-2 near-tie in the dense run;
   3. prefix reuse skips prefill steps without changing a single token, and
      the int8-paged cache footprint lands >= 3x below dense f32;
   4. the host allocator's machine-checkable contract
@@ -250,8 +251,10 @@ class TestPagedEngineIdentity:
     @pytest.mark.parametrize("arch", ARCHS)
     def test_paged_int8_matches_dense(self, arch):
         """Quantize-on-write pages keep greedy decoding token-identical at
-        reduced test scale (the margin between top-2 logits dwarfs the
-        per-token int8 rounding)."""
+        reduced test scale wherever the dense run's top-2 logit margin
+        exceeds the per-token int8 rounding step (1/127 of the logit
+        scale).  A request may part ways only at such a near-tie, and must
+        match token for token up to it."""
         cfg = _cfg(arch)
         params = _params(cfg)
         reqs = _requests(cfg, MIXED)
@@ -260,7 +263,19 @@ class TestPagedEngineIdentity:
         q = ServeEngine(params, cfg, batch_slots=3, max_len=64,
                         prefill_chunk=4, paged=True, page_size=4,
                         cache_fmt="int8").generate(reqs)
-        assert q == dense
+        assert [len(t) for t in q] == [len(t) for t in dense]
+        for req, d_toks, q_toks in zip(reqs, dense, q):
+            if q_toks == d_toks:
+                continue
+            j = next(i for i, (a, b) in enumerate(zip(d_toks, q_toks))
+                     if a != b)
+            seq = jnp.asarray([req.prompt + d_toks[:j]])
+            hidden, _ = T.forward(params, {"tokens": seq}, cfg)
+            logits = np.asarray(T._head_logits(params, hidden[:, -1:], cfg))
+            top2 = np.sort(logits.ravel())[-2:]
+            assert top2[1] - top2[0] < np.abs(logits).max() / 127.0, (
+                f"int8 pages flipped token {j} at a top-2 margin of "
+                f"{top2[1] - top2[0]:.4g}, not a near-tie")
 
     def test_fp8_pages_serve(self):
         cfg = _cfg("yi-9b")

@@ -107,7 +107,7 @@ class TestShardingRules:
 
     def test_current_mesh_inside_context(self):
         mesh = make_debug_mesh(1, 1)
-        with mesh:
+        with jax.set_mesh(mesh):
             got = shd.current_mesh()
             assert got is not None
             assert dict(got.shape) == {"data": 1, "model": 1}
