@@ -1,0 +1,17 @@
+"""idle_admit.backlog: device idle time inside the traced window that the
+trace reduction gives to the program's ``vision.admit`` span (taking lanes,
+uploading each image, stacking the batch), as a share (%) of the window.
+None where the trace names no ``vision.*`` span at all (a program without
+the spans)."""
+
+SPAN = "vision.admit"
+
+
+def read(ctx):
+    red = ctx["trace"]
+    if red is None or red["window_s"] <= 0:
+        return None
+    idle = dict(red["idle_gaps"])
+    if not any(name.startswith("vision.") for name in idle):
+        return None
+    return 100.0 * idle.get(SPAN, 0.0) / red["window_s"]

@@ -19,7 +19,15 @@ Two exposition formats:
     ``_bucket``/``_sum``/``_count`` series for histograms).
 
 The module-level :data:`REGISTRY` is the process default; engines and the
-dispatcher record into it via the convenience constructors below.
+dispatcher record into it via the convenience constructors below, and only
+while ``optrace`` is enabled.  Two counters record always, whatever
+``optrace`` says: ``jax_compiles_total{fun}`` and
+``jax_compile_seconds_total{fun}``, fed by a ``jax.monitoring`` listener on
+JAX's backend-compile event (a compile, or a load from the persistent
+compile cache), labeled by the compiled function's name -- so a benchmark
+or an operator sees set-up's compile time, and which step recompiled,
+without turning telemetry on.  The listener runs only when something
+compiles, never on the hot path.
 """
 from __future__ import annotations
 
@@ -317,3 +325,32 @@ def prometheus_text() -> str:
 
 def clear() -> None:
     REGISTRY.clear()
+
+
+# ------------------------------------------------------ compile counters
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+COMPILE_METRICS = ("jax_compiles_total", "jax_compile_seconds_total")
+_counting_compiles = False
+
+
+def _on_compile(event: str, duration: float, **kw: Any) -> None:
+    if event != COMPILE_EVENT:
+        return
+    fun = str(kw.get("fun_name", "unknown"))
+    counter(COMPILE_METRICS[0], "backend compiles (or compile-cache loads) "
+            "by function", labels=("fun",)).inc(fun=fun)
+    counter(COMPILE_METRICS[1], "seconds in backend compile (or "
+            "compile-cache load) by function", labels=("fun",)).inc(
+                duration, fun=fun)
+
+
+def count_compiles() -> None:
+    """Register the compile listener once per process (idempotent)."""
+    global _counting_compiles
+    if not _counting_compiles:
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _counting_compiles = True
+
+
+count_compiles()

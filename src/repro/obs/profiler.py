@@ -86,10 +86,9 @@ def wall(name: str, **args: Any) -> Iterator[WallScope]:
     scope = WallScope(name)
     t0 = time.perf_counter()
     try:
-        # host-side TraceAnnotation: when a jax.profiler capture is
-        # running, the wall scope shows up on the same timeline as the
-        # named device scopes it encloses
-        with annotate.host_scope(name, enabled=optrace.enabled()):
+        # the host span: on the profiler's timeline beside the named device
+        # scopes it encloses, and in optrace's ring while telemetry is on
+        with annotate.host_scope(name, cat="wall", **args):
             yield scope
     finally:
         scope.elapsed_s = time.perf_counter() - t0
@@ -97,5 +96,3 @@ def wall(name: str, **args: Any) -> Iterator[WallScope]:
             metrics.histogram(
                 "obs_wall_seconds", "measured wall time by scope",
                 labels=("scope",)).observe(scope.elapsed_s, scope=name)
-            optrace.add_span(name, t0, scope.elapsed_s, cat="wall",
-                             args=args)

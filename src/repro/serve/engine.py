@@ -647,10 +647,12 @@ class ServeEngine:
                     tokens[b, 0] = s.last_tok
                     valid[b, 0] = True
             self.rng, sub = jax.random.split(self.rng)
-            t_step = time.perf_counter() if obs_on else 0.0
             ledger0 = (_obs.traced_totals()
                        if obs_on and C not in self._traced_step_cost else None)
-            with _ann.host_scope("serve_step", enabled=obs_on):
+            with _ann.host_scope(
+                    "serve_step", step=steps, cat="serve", width=C,
+                    prefill_slots=sum(s.state == "prefill" for s in slots),
+                    decode_slots=sum(s.state == "decode" for s in slots)):
                 nxt, caches = self._step(self.params, caches,
                                          jnp.asarray(tokens),
                                          jnp.asarray(valid), sub)
@@ -668,15 +670,6 @@ class ServeEngine:
                 for k in modeled:
                     modeled[k] += cost[k]
                 covered_steps += 1
-            if obs_on:
-                _obs.add_span(
-                    "serve_step", t_step, time.perf_counter() - t_step,
-                    cat="serve", args={
-                        "step": steps, "width": C,
-                        "prefill_slots": sum(
-                            1 for s in slots if s.state == "prefill"),
-                        "decode_slots": sum(
-                            1 for s in slots if s.state == "decode")})
             steps += 1
             steps_by_width[C] += 1
             n_prefill += sum(fed)

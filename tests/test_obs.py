@@ -357,7 +357,8 @@ class TestOffByDefault:
         engine = ServeEngine(params, cfg, batch_slots=2, max_len=16,
                              prefill_chunk=4)
         engine.generate(_requests(cfg, [(3, 3), (5, 2)]))
-        assert len(metrics.REGISTRY) == 0      # zero metric objects
+        # zero metric objects but the always-on compile counters
+        assert set(metrics.snapshot()) <= set(metrics.COMPILE_METRICS)
         assert optrace.events() == []
         assert optrace.spans() == []
 
@@ -403,7 +404,8 @@ class TestProfiler:
         with profiler.wall("unit") as scope:
             scope.ready(jnp.ones((2,)))
         assert scope.elapsed_s > 0             # timing still returned
-        assert len(metrics.REGISTRY) == 0      # nothing recorded
+        # nothing recorded but the always-on compile counters
+        assert set(metrics.snapshot()) <= set(metrics.COMPILE_METRICS)
 
 
 # ---------------------------------------------------------------------------
@@ -599,6 +601,83 @@ class TestAnnotate:
         with annotate.host_scope("serve_step", enabled=False):
             ran.append(2)
         assert ran == [1, 2]
+
+    def test_host_scope_records_optrace_span_when_enabled(self):
+        with annotate.host_scope("unit_host", step=3):
+            pass
+        assert optrace.spans() == []
+        optrace.enable()
+        with annotate.host_scope("unit_host", step=3, cat="unit", lane=1):
+            pass
+        (span,) = optrace.spans()
+        assert (span.name, span.cat) == ("unit_host", "unit")
+        assert span.args == {"lane": 1, "step": 3}
+
+    def test_vision_phases_land_in_a_plain_profiler_capture(self, tmp_path):
+        """With ``repro.obs`` off, a capture started by ``jax.profiler``
+        itself holds, per engine step, one ``vision.admit``,
+        ``vision.step`` and ``vision.fetch`` host span, in that order,
+        each inside the call's ``vision.infer``."""
+        import glob
+        from repro.configs import get_vision_config
+        from repro.vision import models as vision_models
+        from repro.vision.engine import ImageRequest, VisionEngine
+        cfg = get_vision_config("resnet50", reduced=True)
+        engine = VisionEngine(vision_models.init(KEY, cfg), cfg,
+                              batch_slots=2)
+        rng = np.random.default_rng(0)
+        reqs = [ImageRequest(rng.random((*cfg.input_hw, 3), np.float32))
+                for _ in range(3)]
+        engine.infer(reqs)                     # compile outside the capture
+        assert not optrace.enabled()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            engine.infer(reqs)
+        finally:
+            jax.profiler.stop_trace()
+        st = engine.last_stats
+        assert st["steps"] == 2 and st["d2h_copies"] == 3
+        assert st["h2d_bytes"] == 3 * reqs[0].image.nbytes
+        assert min(st["admit_s"], st["step_s"], st["fetch_s"]) > 0
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        pd = jax.profiler.ProfileData.from_file(path)
+        spans = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                       for plane in pd.planes if plane.name == "/host:CPU"
+                       for line in plane.lines for e in line.events
+                       if e.name.startswith("vision."))
+        (lo, hi, _), = [s for s in spans if s[2] == "vision.infer"]
+        phases = [s for s in spans if s[2] != "vision.infer"]
+        assert [n for _, _, n in phases] == \
+            ["vision.admit", "vision.step", "vision.fetch"] * st["steps"]
+        assert all(lo <= s and e <= hi for s, e, _ in phases)
+        assert all(a[1] <= b[0] for a, b in zip(phases, phases[1:]))
+
+
+# ---------------------------------------------------------------------------
+# compile counters (always on)
+# ---------------------------------------------------------------------------
+
+
+class TestCompileCounters:
+    def test_fresh_compile_is_counted_by_function(self):
+        assert not optrace.enabled()
+        def unit_compile_probe(x):
+            return x * 5.0 - 1.0
+        fun = "jit(unit_compile_probe)"
+
+        def reading(name):
+            m = metrics.REGISTRY.get(name)
+            return m.value(fun=fun) if m is not None else 0.0
+
+        n0 = reading("jax_compiles_total")
+        s0 = reading("jax_compile_seconds_total")
+        jax.jit(unit_compile_probe)(jnp.ones((3, 5)))
+        assert reading("jax_compiles_total") == n0 + 1
+        assert reading("jax_compile_seconds_total") > s0
+        metrics.count_compiles()               # idempotent: one listener
+        jax.jit(unit_compile_probe)(jnp.ones((5, 3)))
+        assert reading("jax_compiles_total") == n0 + 2
 
 
 # ---------------------------------------------------------------------------
