@@ -23,10 +23,10 @@ flips the step policy to ``precision="int8"`` so every conv/dense dispatches
 the int8 kernels -- quantize once, serve many.
 
 ``last_stats`` reports throughput (img/s), per-request latency percentiles,
-mean batch occupancy, and the host time, bytes uploaded and result copies of
-each phase of the loop (admit, step, fetch) for the most recent ``infer``
-call; the same phases are ``vision.*`` spans in any ``jax.profiler``
-capture.
+mean batch occupancy, and the host time of each phase of the loop (admit,
+step, fetch), the bytes uploaded and the device-to-host copies (one per
+output leaf per step) for the most recent ``infer`` call; the same phases
+are ``vision.*`` spans in any ``jax.profiler`` capture.
 """
 from __future__ import annotations
 
@@ -61,6 +61,18 @@ def step_batch(n_admitted: int, batch_slots: int) -> int:
     :func:`declared_step_batches` to prove it."""
     del n_admitted
     return batch_slots
+
+
+def fetch_rows(n_live: int, batch_slots: int) -> int:
+    """Rows of each step output the vision engine copies to the host, as a
+    pure function of how many lanes are live (lanes are packed from 0):
+    the smallest power of two that holds them, capped at ``batch_slots``.
+    A full step copies the whole batch; a sparse one at most twice its
+    live rows, from one compiled slice per power of two."""
+    rows = 1
+    while rows < n_live:
+        rows *= 2
+    return min(rows, batch_slots)
 
 
 def declared_step_batches(batch_slots: int) -> tuple[int, ...]:
@@ -129,6 +141,7 @@ class VisionEngine:
         jitted = jax.jit(make_infer_step(cfg, policy=pol),
                          out_shardings=step_out)
         self._step = self._under_mesh(jitted)
+        self._prefixes_warm = False
         self.last_stats: dict[str, Any] | None = None
 
     def declared_step_batches(self) -> tuple[int, ...]:
@@ -201,12 +214,29 @@ class VisionEngine:
                          self.cfg.pdtype)
 
     def warmup(self) -> None:
-        """Compile the (single) step shape outside any timed region."""
+        """Compile the (single) step shape and the fetch's slices outside
+        any timed region."""
         zero = jnp.zeros((self.batch_slots, *self.cfg.input_hw,
                           self.cfg.in_channels), self.cfg.pdtype)
         if self._batch_sharding is not None:
             zero = jax.device_put(zero, self._batch_sharding)
-        jax.block_until_ready(self._step(self.params, zero))
+        self._fetch(jax.block_until_ready(self._step(self.params, zero)),
+                    self.batch_slots)
+
+    def _fetch(self, out, n_live: int):
+        """The step's outputs on the host: rows ``[0, fetch_rows(n_live))``
+        of each leaf in one copy per leaf, every copy started before any is
+        waited on.  The first call also compiles every smaller prefix's
+        slice, so no later call compiles one, whatever its occupancy."""
+        B = self.batch_slots
+        if not self._prefixes_warm:
+            for rows in {fetch_rows(n, B) for n in range(1, B)} - {B}:
+                jax.block_until_ready(jax.tree.map(lambda a: a[:rows], out))
+            self._prefixes_warm = True
+        rows = fetch_rows(n_live, B)
+        if rows < B:
+            out = jax.tree.map(lambda a: a[:rows], out)
+        return jax.device_get(out)
 
     def _warm_geometries(self, requests: list[ImageRequest]) -> int:
         """Pre-trace the letterbox resize for every input geometry in the
@@ -237,10 +267,12 @@ class VisionEngine:
         ``vision.admit`` (take lanes, upload or letterbox each image, zero
         the empty lanes, stack), ``vision.step`` (the jitted step and its
         ``block_until_ready``; a step annotation numbered by step) and
-        ``vision.fetch`` (every lane's result back to the host), all
-        inside one ``vision.infer``.  ``last_stats`` counts the same
-        phases: ``admit_s``/``step_s``/``fetch_s`` host seconds,
-        ``h2d_bytes`` uploaded and ``d2h_copies`` made."""
+        ``vision.fetch`` (the rows :func:`fetch_rows` gives of the step's
+        output to the host, one copy per output leaf, then split into
+        lanes there), all inside one ``vision.infer``.  ``last_stats``
+        counts the same phases: ``admit_s``/``step_s``/``fetch_s`` host
+        seconds, ``h2d_bytes`` uploaded and ``d2h_copies`` made (steps x
+        output leaves)."""
         self._validate(requests)
         if self.letterbox:
             # compile-per-geometry happens HERE, before the clock starts
@@ -289,15 +321,16 @@ class VisionEngine:
             t_fetch = time.perf_counter()
             done = t_fetch - t0
             with _ann.host_scope("vision.fetch"):
+                host_out = self._fetch(out, len(lanes))
                 for b, ridx in enumerate(lanes):
                     outputs[ridx] = jax.tree.map(
-                        lambda a, b=b: np.asarray(a[b]), out)
+                        lambda a, b=b: a[b], host_out)
                     lat[ridx] = done - requests[ridx].arrival_s
                     compute_s[ridx] = done - now
             phase_s["admit_s"] += t_step - t_admit
             phase_s["step_s"] += t_fetch - t_step
             phase_s["fetch_s"] += time.perf_counter() - t_fetch
-            d2h_copies += len(lanes) * len(jax.tree.leaves(out))
+            d2h_copies += len(jax.tree.leaves(out))
             steps += 1
             occupancy += len(lanes)
 
@@ -368,5 +401,6 @@ class VisionEngine:
             "vision_h2d_bytes_total", "image bytes uploaded").inc(
                 st["h2d_bytes"])
         _obs_metrics.counter(
-            "vision_d2h_copies_total", "device-to-host result copies").inc(
+            "vision_d2h_copies_total",
+            "device-to-host result copies, one per output leaf per step").inc(
                 st["d2h_copies"])

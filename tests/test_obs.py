@@ -636,7 +636,7 @@ class TestAnnotate:
         finally:
             jax.profiler.stop_trace()
         st = engine.last_stats
-        assert st["steps"] == 2 and st["d2h_copies"] == 3
+        assert st["steps"] == 2 and st["d2h_copies"] == 2
         assert st["h2d_bytes"] == 3 * reqs[0].image.nbytes
         assert min(st["admit_s"], st["step_s"], st["fetch_s"]) > 0
         (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),

@@ -21,7 +21,7 @@ from repro.configs import VISION_IDS, get_vision_config
 from repro.core import workloads
 from repro.core.im2col_model import lower_to_gemm
 from repro.vision import models, postprocess, preprocess, trace
-from repro.vision.engine import ImageRequest, VisionEngine
+from repro.vision.engine import ImageRequest, VisionEngine, fetch_rows
 
 KEY = jax.random.PRNGKey(0)
 
@@ -122,6 +122,59 @@ class TestEngine:
         for i, o in enumerate(outs):
             np.testing.assert_allclose(o["det2"], np.asarray(want["det2"][i]),
                                        rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("name", ["resnet50", "yolov3-tiny"])
+    def test_fetch_copies_each_output_leaf_once_per_step(self, zoo, name):
+        """Each step's output tree comes to the host in one copy per
+        leaf and is split into lanes there: the rows are bit-identical to
+        the per-lane slices of the same padded batches through
+        ``models.apply``, and each is a host array."""
+        cfg, params, _ = zoo[name]
+        rng = np.random.default_rng(4)
+        imgs = rng.normal(size=(5, *cfg.input_hw, 3)).astype(np.float32)
+        eng = VisionEngine(params, cfg, batch_slots=4)
+        outs = eng.infer([ImageRequest(image=im) for im in imgs])
+        apply = jax.jit(lambda p, x: models.apply(p, x, cfg))
+        padded = np.concatenate([imgs, np.zeros_like(imgs[:3])])
+        want = [jax.device_get(apply(params, padded[i:i + 4]))
+                for i in (0, 4)]
+        st = eng.last_stats
+        assert st["steps"] == 2
+        assert st["d2h_copies"] == 2 * len(jax.tree.leaves(want[0]))
+        for i, out in enumerate(outs):
+            row = jax.tree.map(lambda a: a[i % 4], want[i // 4])
+            assert jax.tree.structure(out) == jax.tree.structure(row)
+            for got, exp in zip(jax.tree.leaves(out), jax.tree.leaves(row)):
+                assert type(got) is np.ndarray
+                np.testing.assert_array_equal(got, exp)
+
+    @pytest.mark.parametrize("n_live,batch_slots,rows", [
+        (1, 32, 1), (2, 32, 2), (3, 32, 4), (16, 32, 16), (17, 32, 32),
+        (32, 32, 32), (5, 6, 6), (1, 1, 1)])
+    def test_fetch_rows_is_the_live_power_of_two_prefix(
+            self, n_live, batch_slots, rows):
+        assert fetch_rows(n_live, batch_slots) == rows
+
+    def test_no_compile_after_first_call_at_any_occupancy(self, zoo):
+        """The first call compiles the step and every prefix slice the
+        fetch can take; later calls compile nothing, whatever their
+        occupancy."""
+        from repro.obs import metrics
+
+        def compiles():
+            snap = metrics.snapshot().get("jax_compiles_total", {})
+            return sum(v["value"] for v in snap.get("values", []))
+
+        cfg, params, _ = zoo["yolov3-tiny"]
+        rng = np.random.default_rng(5)
+        imgs = rng.normal(size=(8, *cfg.input_hw, 3)).astype(np.float32)
+        eng = VisionEngine(params, cfg, batch_slots=8)
+        eng.infer([ImageRequest(image=imgs[0])])
+        n0 = compiles()
+        for n in range(1, 9):
+            outs = eng.infer([ImageRequest(image=im) for im in imgs[:n]])
+            assert len(outs) == n
+        assert compiles() == n0
 
     def test_letterbox_compiles_outside_timed_loop(self, zoo):
         """Regression: first-seen letterbox geometries compiled INSIDE the
