@@ -24,14 +24,15 @@ the int8 kernels -- quantize once, serve many.
 
 ``last_stats`` reports throughput (img/s), per-request latency percentiles,
 mean batch occupancy, and the host time of each phase of the loop (admit,
-step, fetch), the bytes uploaded and the device-to-host copies (one per
-output leaf per step) for the most recent ``infer`` call; the same phases
-are ``vision.*`` spans in any ``jax.profiler`` capture.
+step, fetch), the bytes uploaded, and the device-to-host copies (one per
+output leaf per step) and their bytes for the most recent ``infer`` call;
+the same phases are ``vision.*`` spans in any ``jax.profiler`` capture.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import math
 import time
 from typing import Any
 
@@ -73,6 +74,13 @@ def fetch_rows(n_live: int, batch_slots: int) -> int:
     while rows < n_live:
         rows *= 2
     return min(rows, batch_slots)
+
+
+def fetch_nbytes(out, rows: int) -> int:
+    """Bytes the fetch copies of step output ``out`` (arrays or shapes):
+    ``rows`` rows of every leaf."""
+    return sum(rows * math.prod(a.shape[1:]) * np.dtype(a.dtype).itemsize
+               for a in jax.tree.leaves(out))
 
 
 def declared_step_batches(batch_slots: int) -> tuple[int, ...]:
@@ -271,8 +279,8 @@ class VisionEngine:
         output to the host, one copy per output leaf, then split into
         lanes there), all inside one ``vision.infer``.  ``last_stats``
         counts the same phases: ``admit_s``/``step_s``/``fetch_s`` host
-        seconds, ``h2d_bytes`` uploaded and ``d2h_copies`` made (steps x
-        output leaves)."""
+        seconds, ``h2d_bytes`` uploaded, ``d2h_copies`` made (steps x
+        output leaves) and ``d2h_bytes`` copied (:func:`fetch_nbytes`)."""
         self._validate(requests)
         if self.letterbox:
             # compile-per-geometry happens HERE, before the clock starts
@@ -290,6 +298,7 @@ class VisionEngine:
         phase_s = {"admit_s": 0.0, "step_s": 0.0, "fetch_s": 0.0}
         h2d_bytes = 0
         d2h_copies = 0
+        d2h_bytes = 0
         t0 = time.perf_counter()
 
         while pending:
@@ -331,6 +340,7 @@ class VisionEngine:
             phase_s["step_s"] += t_fetch - t_step
             phase_s["fetch_s"] += time.perf_counter() - t_fetch
             d2h_copies += len(jax.tree.leaves(out))
+            d2h_bytes += fetch_nbytes(out, fetch_rows(len(lanes), B))
             steps += 1
             occupancy += len(lanes)
 
@@ -360,6 +370,7 @@ class VisionEngine:
             **phase_s,
             "h2d_bytes": h2d_bytes,
             "d2h_copies": d2h_copies,
+            "d2h_bytes": d2h_bytes,
             "mapper_cache": mapper_cache_stats(),
         }
         if self.mesh is not None:
@@ -404,3 +415,6 @@ class VisionEngine:
             "vision_d2h_copies_total",
             "device-to-host result copies, one per output leaf per step").inc(
                 st["d2h_copies"])
+        _obs_metrics.counter(
+            "vision_d2h_bytes_total", "result bytes copied to the host").inc(
+                st["d2h_bytes"])

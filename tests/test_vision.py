@@ -21,7 +21,8 @@ from repro.configs import VISION_IDS, get_vision_config
 from repro.core import workloads
 from repro.core.im2col_model import lower_to_gemm
 from repro.vision import models, postprocess, preprocess, trace
-from repro.vision.engine import ImageRequest, VisionEngine, fetch_rows
+from repro.vision.engine import (ImageRequest, VisionEngine, fetch_nbytes,
+                                 fetch_rows, make_infer_step)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -147,6 +148,42 @@ class TestEngine:
             for got, exp in zip(jax.tree.leaves(out), jax.tree.leaves(row)):
                 assert type(got) is np.ndarray
                 np.testing.assert_array_equal(got, exp)
+
+    @pytest.mark.parametrize("name", ["resnet50", "yolov3-tiny"])
+    def test_d2h_bytes_count_the_fetched_rows(self, zoo, name):
+        """``d2h_bytes`` is each step's fetched rows times the output
+        leaves' row bytes, summed over steps, and feeds the registry
+        counter when telemetry is on."""
+        from repro.obs import metrics, optrace
+        cfg, params, _ = zoo[name]
+        rng = np.random.default_rng(5)
+        imgs = rng.normal(size=(5, *cfg.input_hw, 3)).astype(np.float32)
+        eng = VisionEngine(params, cfg, batch_slots=4)
+        metrics.clear()
+        optrace.enable()
+        try:
+            outs = eng.infer([ImageRequest(image=im) for im in imgs])
+            counted = metrics.snapshot()["vision_d2h_bytes_total"]["values"]
+        finally:
+            optrace.disable()
+            metrics.clear()
+        row_bytes = sum(a.nbytes for a in jax.tree.leaves(outs[0]))
+        st = eng.last_stats
+        assert st["steps"] == 2
+        assert st["d2h_bytes"] == (fetch_rows(4, 4) + fetch_rows(1, 4)) \
+            * row_bytes == 5 * row_bytes
+        assert sum(r["value"] for r in counted) == st["d2h_bytes"]
+
+    def test_yolov3_full_step_fetches_its_three_maps(self):
+        """At 416x416 and 32 lanes a full step copies 115,839,360 bytes
+        (13^2 + 26^2 + 52^2 pixels x 255 channels x 4 bytes x 32), read
+        from the step's output shapes without running it."""
+        cfg = get_vision_config("yolov3")
+        params = jax.eval_shape(lambda k: models.init(k, cfg), KEY)
+        images = jax.ShapeDtypeStruct((32, *cfg.input_hw, cfg.in_channels),
+                                      cfg.pdtype)
+        out = jax.eval_shape(make_infer_step(cfg), params, images)
+        assert fetch_nbytes(out, fetch_rows(32, 32)) == 115_839_360
 
     @pytest.mark.parametrize("n_live,batch_slots,rows", [
         (1, 32, 1), (2, 32, 2), (3, 32, 4), (16, 32, 16), (17, 32, 32),
