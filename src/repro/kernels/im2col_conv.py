@@ -18,7 +18,20 @@ into its ``sh * sw`` stride phases (phase ``(a, b)`` holds rows ``a::sh``,
 cols ``b::sw``), so tap ``(dh, dw)`` of a strided window is a *unit-stride*
 slice of phase ``(dh % sh, dw % sw)`` at offset ``(dh // sh, dw // sw)``.
 Only phases some tap reads are kept (a 1x1/2 conv keeps one), so each IFMAP
-element still reaches VMEM once per tile.
+element still reaches VMEM once per tile.  The split never indexes
+``x[:, a::sh, b::sw]``: jnp lowers a stepped index to a ``gather`` over an
+iota of every (row, col) pair, which the TPU runs as a gather fusion many
+times slower than a dense copy of the same bytes.  Instead the padded input
+is cropped to ``Hq * sh x Wq * sw`` and reshaped so the row phase gets its
+own axis and the column phase folds into the channel axis,
+``(N, Hq, sh, Wq, sw * C)``; phase ``(a, b)`` -- still rows ``a::sh``, cols
+``b::sw`` -- is then the unit-stride slice ``[:, :, a, :, b*C:(b+1)*C]``.
+One strided ``lax.slice`` per phase is gather-free too, but slower on a
+TPU v5e: it split YOLOv3's 416x416x32 downsample input (batch 32) in 51 ms
+against the reshape's 39 ms, was slower on the split alone at every other
+downsample timed (ResNet-50's 3-channel stem included), and left the whole
+YOLOv3-416 step at 180 ms of device time against 168 ms.  So the reshape
+serves every stride but 1.
 
 Halo handling: Pallas block offsets are multiples of the block shape, so the
 ``(kh - 1) // sh`` extra rows a tile needs below it come from a second,
@@ -92,7 +105,9 @@ def phase_split(x: jax.Array, kh: int, kw: int, stride, padding,
     if phases == [(0, 0)] and sh == sw == 1:
         x_ph = x_p[:, None, :Hq, :Wq]
     else:
-        x_ph = jnp.stack([x_p[:, a::sh, b::sw][:, :Hq, :Wq]
+        Cp = x_p.shape[3]
+        r = x_p[:, :Hq * sh, :Wq * sw].reshape(N, Hq, sh, Wq, sw * Cp)
+        x_ph = jnp.stack([r[:, :, a, :, b * Cp:(b + 1) * Cp]
                           for a, b in phases], axis=1)
     taps = tuple((phases.index((dh % sh, dw % sw)), dh // sh, dw // sw,
                   dh, dw) for dh in range(kh) for dw in range(kw))
