@@ -1,4 +1,4 @@
-"""Hardware constants used by the mapper and the roofline analysis.
+"""Hardware constants used by the mapper and the kernel blockings.
 
 TPU v5e per chip (Google Cloud documentation, "TPU v5e"):
   * 197 TFLOP/s bf16 peak (MXU), 393 TOP/s int8
@@ -8,16 +8,10 @@ TPU v5e per chip (Google Cloud documentation, "TPU v5e"):
 
 The MXU is itself a 128x128 systolic array -- the natural "array shape" for
 the paper's runtime model when reasoning about TPU GeMM mapping.
-
-Roofline peaks are looked up by the running device's ``device_kind``
-(:func:`chip_for`); an accelerator missing from :data:`CHIPS` is an error,
-never a silent v5e default.
 """
 from __future__ import annotations
 
 import dataclasses
-
-import jax
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,26 +36,6 @@ TPU_V5E = ChipSpec(
     vmem_bytes=128 * 1024**2,
     mxu_shape=(128, 128),
 )
-
-# Peaks keyed by ``jax.Device.device_kind``.
-CHIPS: dict[str, ChipSpec] = {"TPU v5 lite": TPU_V5E}
-
-
-def chip_for(device=None) -> ChipSpec | None:
-    """Published peaks of ``device`` (default: the first JAX device).
-
-    None on the CPU, whose runs have no roofline ("not measured"); an
-    accelerator whose ``device_kind`` is not in :data:`CHIPS` raises."""
-    device = jax.devices()[0] if device is None else device
-    if device.platform == "cpu":
-        return None
-    try:
-        return CHIPS[device.device_kind]
-    except KeyError:
-        raise ValueError(
-            f"no published peaks for device_kind {device.device_kind!r}; "
-            f"add it to repro.core.hw.CHIPS (known: {sorted(CHIPS)})"
-        ) from None
 
 
 # Working-set budget a single pallas_call block set should stay under.

@@ -148,12 +148,6 @@ def main() -> None:
               f"{stats['steps']} steps, p50 latency {np.percentile(lat, 50):.2f}s, "
               f"p99 {np.percentile(lat, 99):.2f}s)")
 
-    if stats and "attribution" in stats:
-        att = stats["attribution"]
-        print(f"attribution: modeled {att['modeled_flops']:.3g} FLOPs, "
-              f"step coverage {att['modeled_step_coverage']:.0%}, "
-              f"roofline {att['roofline']}")
-
     if obs_on:
         import repro.obs as obs
         from repro.obs import profiler, streaming

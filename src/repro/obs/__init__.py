@@ -22,8 +22,8 @@ Or from the shell::
     python -m repro.obs --smoke --trace-out trace.json \
         --metrics-out metrics.json
 """
-from repro.obs import (annotate, attribution, metrics, optrace, profiler,
-                       streaming, trace_export)
+from repro.obs import (annotate, metrics, optrace, profiler, streaming,
+                       trace_export)
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                REGISTRY, host_clean)
 from repro.obs.optrace import (OpEvent, SpanEvent, configure, disable,
@@ -32,8 +32,8 @@ from repro.obs.trace_export import (chrome_trace, validate_chrome_trace,
                                     write_chrome_trace)
 
 __all__ = [
-    "annotate", "attribution", "metrics", "optrace", "profiler",
-    "streaming", "trace_export",
+    "annotate", "metrics", "optrace", "profiler", "streaming",
+    "trace_export",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "host_clean",
     "OpEvent", "SpanEvent", "configure", "disable", "enable", "enabled",

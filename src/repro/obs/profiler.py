@@ -6,13 +6,12 @@ Two layers, both no-ops unless explicitly started:
     ``stop_trace`` so a CLI flag can capture a device profile into a
     directory (view with TensorBoard or xprof).  Failures to start (e.g.
     a platform without profiler support) downgrade to a warning -- the
-    modeled telemetry must never die because the measured layer can't
+    host-side telemetry must never die because the profiler can't
     attach.
   * :func:`wall` -- a context manager that times a host scope with
     ``block_until_ready`` on the values you hand back, records the wall
     time into the ``obs_wall_seconds{scope=...}`` histogram and an
-    optrace span, so modeled FLOPs/bytes ratios can be paired with
-    measured wall time at the same call sites.
+    optrace span.
 """
 from __future__ import annotations
 

@@ -40,7 +40,6 @@ from repro.models import transformer as T
 from repro.parallel import sharding as shd
 from repro.parallel.specs import make_param_spec_fn
 from repro.obs import annotate as _ann
-from repro.obs import attribution as _attr
 from repro.obs import metrics as _obs_metrics, optrace as _obs
 from repro.obs import streaming as _streaming
 from repro.serve import kvcache as KV
@@ -401,11 +400,6 @@ class ServeEngine:
                 T.reset_slots, donate_argnums=(0,),
                 out_shardings=reset_p_out))
         self.last_stats: dict[str, Any] | None = None
-        # per-trace modeled cost of one chunk step, keyed by token width:
-        # jitted steps never hit the op ring (one dispatch per compilation),
-        # so the modeled FLOPs/bytes are captured from the traced-cost
-        # ledger the first time each width is traced with telemetry on
-        self._traced_step_cost: dict[int, dict[str, float]] = {}
 
     def _under_mesh(self, fn):
         """Wrap a jitted callable so every call (and thus every trace) runs
@@ -623,8 +617,6 @@ class ServeEngine:
         steps = 0
         steps_by_width: collections.Counter = collections.Counter()
         n_prefill = 0
-        modeled = {"flops": 0.0, "bytes": 0.0, "energy_j": 0.0}
-        covered_steps = 0
         # publish pool/mapper gauges on the streaming cadence for the
         # duration of this call (no-op without an active exporter)
         streaming_on = obs_on and _streaming.add_collector(
@@ -647,8 +639,6 @@ class ServeEngine:
                     tokens[b, 0] = s.last_tok
                     valid[b, 0] = True
             self.rng, sub = jax.random.split(self.rng)
-            ledger0 = (_obs.traced_totals()
-                       if obs_on and C not in self._traced_step_cost else None)
             with _ann.host_scope(
                     "serve_step", step=steps, cat="serve", width=C,
                     prefill_slots=sum(s.state == "prefill" for s in slots),
@@ -657,19 +647,6 @@ class ServeEngine:
                                          jnp.asarray(tokens),
                                          jnp.asarray(valid), sub)
                 nxt = np.asarray(nxt)   # host transfer: device sync point
-            if ledger0 is not None:
-                after = _obs.traced_totals()
-                if after["count"] > ledger0["count"]:
-                    # this step traced: the ledger delta IS the modeled
-                    # per-execution cost of a width-C chunk step
-                    self._traced_step_cost[C] = {
-                        k: after[k] - ledger0[k]
-                        for k in ("flops", "bytes", "energy_j")}
-            cost = self._traced_step_cost.get(C) if obs_on else None
-            if cost is not None:
-                for k in modeled:
-                    modeled[k] += cost[k]
-                covered_steps += 1
             steps += 1
             steps_by_width[C] += 1
             n_prefill += sum(fed)
@@ -716,11 +693,6 @@ class ServeEngine:
             self.last_stats["prefix_hit_tokens"] = \
                 self.pool.hit_tokens - hit_tok0
         if obs_on:
-            # achieved-intensity attribution: modeled step cost from the
-            # traced ledger vs this call's measured wall time
-            self.last_stats["attribution"] = _attr.engine_row(
-                wall_s=wall, modeled=modeled, steps=steps,
-                covered_steps=covered_steps)
             self._publish_metrics(per_req)
         if streaming_on:
             _streaming.remove_collector(self._stream_collector)
